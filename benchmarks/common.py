@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core import EdgentPlanner, alexnet_graph
 from repro.data.synthetic import cifar_like
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models.alexnet import BranchyAlexNet, BranchyAlexNetConfig
 from repro.optim.adamw import adamw_init, adamw_update
 
@@ -26,6 +27,7 @@ KBPS = 125.0  # bytes/s per kbps
 
 @functools.lru_cache(maxsize=1)
 def alexnet_setup():
+    enable_compile_cache()             # before the first compile below
     net = BranchyAlexNet(BranchyAlexNetConfig())
     rng = jax.random.key(0)
     params = net.init(rng)

@@ -62,7 +62,7 @@ def main():
     toks = jnp.asarray(reqs[0].prompt)[None]
     cache = model.init_cache(1, 32, dtype=jnp.float32, enc_len=toks.shape[1])
     h, cache = model.prefill(params, toks, cache)
-    conf = exit_ops.exit_confidence(h, params["embed"])
+    conf = exit_ops.exit_confidence(h, params["embed"], interpret=True)
     print(f"\nfused exit-head on last prefill token: "
           f"token={int(conf['token'][0, 0])} "
           f"conf={float(conf['conf'][0, 0]):.3f} "

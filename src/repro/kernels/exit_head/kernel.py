@@ -76,7 +76,7 @@ def _kernel(h_ref, emb_ref, tok_ref, conf_ref, ent_ref,
 
 
 def exit_confidence_pallas(h2d, emb, *, tile_rows: int = 256,
-                           tile_v: int = 512, interpret: bool = True):
+                           tile_v: int = 512, interpret: bool = False):
     """h2d: [T, D] (already exit-normed); emb: [V, D] tied embedding.
     Returns (token [T] i32, conf [T] f32, entropy [T] f32)."""
     T, D = h2d.shape

@@ -11,7 +11,7 @@ from repro.kernels.exit_head.kernel import exit_confidence_pallas
 
 @partial(jax.jit, static_argnames=("tile_rows", "tile_v", "interpret"))
 def exit_confidence(h, emb, *, tile_rows: int = 256, tile_v: int = 512,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """h: [B, S, D] exit-normed hidden; emb: [V, D].
     Returns dict(token [B,S] i32, conf [B,S] f32, entropy [B,S] f32) —
     same contract as ``repro.kernels.exit_head.ref.exit_confidence``."""

@@ -37,7 +37,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)      # [1, hd]
@@ -67,7 +67,7 @@ def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 
 def decode_attention_pallas(q, k, v, lengths, *, block_k: int = 128,
-                            interpret: bool = True):
+                            interpret: bool = False):
     """q: [B, H, 1, hd]; k/v: [B, KV, T, hd]; lengths: [B] int32.
 
     Returns [B, H, 1, hd].  Row ``b`` attends over ``k[b, :, :lengths[b]]``
@@ -85,23 +85,30 @@ def decode_attention_pallas(q, k, v, lengths, *, block_k: int = 128,
     lengths = jnp.asarray(lengths, jnp.int32)
 
     kern = functools.partial(_kernel, scale=scale, block_k=bk, n_k=nk)
-    grid = (B, H, nk)
+    # per-slot lengths ride in SMEM as a scalar-prefetch operand (a rank-1
+    # VMEM block of B lengths is not a legal TPU tile); every index map
+    # receives the prefetched ref as its trailing argument
     out = pl.pallas_call(
         kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1,), lambda b, h, j: (b,)),
-            pl.BlockSpec((1, 1, 1, hd), lambda b, h, j: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, G=G: (b, h // G, j, 0)),
-            pl.BlockSpec((1, 1, bk, hd), lambda b, h, j, G=G: (b, h // G, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, hd), lambda b, h, j: (b, h, 0, 0)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(B, H, nk),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, hd), lambda b, h, j, _: (b, h, 0, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, j, _, G=G: (b, h // G, j, 0)),
+                pl.BlockSpec((1, 1, bk, hd),
+                             lambda b, h, j, _, G=G: (b, h // G, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, 1, 1, hd),
+                                   lambda b, h, j, _: (b, h, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, 1), jnp.float32),
+                pltpu.VMEM((1, hd), jnp.float32),
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct((B, H, 1, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, hd), jnp.float32),
-        ],
         interpret=interpret,
     )(lengths, q, k, v)
     return out

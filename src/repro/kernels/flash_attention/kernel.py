@@ -67,7 +67,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool = False):
     """q: [B, H, S, hd]; k/v: [B, KV, T, hd].  Returns [B, H, S, hd]."""
     B, H, S, hd = q.shape
     KV, T = k.shape[1], k.shape[2]

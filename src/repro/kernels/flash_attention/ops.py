@@ -15,7 +15,7 @@ from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 @partial(jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
-                    block_k: int = 128, interpret: bool = True):
+                    block_k: int = 128, interpret: bool = False):
     """q: [B, S, H, hd]; k/v: [B, T, KV, hd] -> [B, S, H, hd]."""
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
@@ -27,7 +27,7 @@ def flash_attention(q, k, v, *, causal: bool = True, block_q: int = 128,
 
 @partial(jax.jit, static_argnames=("block_k", "interpret"))
 def decode_attention(q, k, v, lengths, *, block_k: int = 128,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """Arena-row decode attention in the model layout: q [B, 1, H, hd],
     k/v [B, T, KV, hd] (the slot axis first, as DecodeArena stacks them),
     lengths [B] per-slot true lengths -> [B, 1, H, hd]."""

@@ -6,8 +6,8 @@ and run warp-level scans; on TPU the natural decomposition is a *sequential
 grid* over time chunks with the running state [dk, dv] held in VMEM scratch,
 and the intra-chunk part expressed as two MXU matmuls (the [C, C] decay-
 weighted attention matrix, then @ v).  Per-chunk cumulative-decay products
-are computed in-register (cumsum in log space); MIN_LOG_W bounds the ratio
-trick to f32 range for C <= 32.
+are computed in-register (a triangular matmul in log space); MIN_LOG_W
+bounds the ratio trick to f32 range for C <= 32.
 
 Grid: (B*H, S/C), chunks innermost.  One kernel instance handles both RWKV
 semantics (pre-update output + bonus ``u``) and Mamba-2 (post-update).
@@ -37,8 +37,15 @@ def _kernel(q_ref, k_ref, v_ref, lw_ref, s0_ref, u_ref, o_ref, sT_ref,
     vc = v_ref[0].astype(jnp.float32)            # [C, dv]
     lw = jnp.maximum(lw_ref[0].astype(jnp.float32), MIN_LOG_W)
     C = chunk
+    ti = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
+    si = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
 
-    logP = jnp.cumsum(lw, axis=0)                # [C, dk]
+    # inclusive cumsum over the chunk as a lower-triangular matmul (Mosaic
+    # has no cumsum lowering; the MXU does this in one pass)
+    logP = jax.lax.dot_general(
+        jnp.where(si <= ti, 1.0, 0.0), lw, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)      # [C, dk]
     P = jnp.exp(logP)
     k_ = kc / P
     s = state_scr[...]                           # [dk, dv]
@@ -47,18 +54,14 @@ def _kernel(q_ref, k_ref, v_ref, lw_ref, s0_ref, u_ref, o_ref, sT_ref,
         q_ = qc * jnp.exp(logP - lw)             # P_{t-1}
         A = jax.lax.dot_general(q_, k_, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        ti = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-        si = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
         A = jnp.where(si < ti, A, 0.0)
-        u = u_ref[0].astype(jnp.float32)         # [dk]
-        diag = jnp.sum(qc * u[None, :] * kc, axis=1)
+        u = u_ref[0].astype(jnp.float32)         # [1, dk]
+        diag = jnp.sum(qc * u * kc, axis=1)
         A = A + jnp.where(si == ti, diag[:, None], 0.0)
     else:
         q_ = qc * P                              # P_t
         A = jax.lax.dot_general(q_, k_, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
-        ti = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
-        si = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
         A = jnp.where(si <= ti, A, 0.0)
 
     intra = jax.lax.dot_general(A, vc, (((1,), (0,)), ((), ())),
@@ -67,9 +70,19 @@ def _kernel(q_ref, k_ref, v_ref, lw_ref, s0_ref, u_ref, o_ref, sT_ref,
                                 preferred_element_type=jnp.float32)
     o_ref[0] = (intra + inter).astype(o_ref.dtype)
 
-    # state update: S' = diag(P_C) S + sum_s (P_C / P_s) k_s v_s^T
-    kP = kc * jnp.exp(logP[-1][None, :] - logP)
-    state_scr[...] = P[-1][:, None] * s + jax.lax.dot_general(
+    # state update: S' = diag(P_C) S + sum_s (P_C / P_s) k_s v_s^T; the
+    # diagonal scaling is a matmul because P_C is a row and S needs it as
+    # a column (static slices only: Mosaic lowers no dynamic_slice)
+    last = jax.lax.slice_in_dim(logP, C - 1, C, axis=0)     # [1, dk]
+    kP = kc * jnp.exp(last - logP)
+    dk = s.shape[0]
+    ri = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 0)
+    ci = jax.lax.broadcasted_iota(jnp.int32, (dk, dk), 1)
+    decay = jnp.where(ri == ci, jnp.exp(last), 0.0)         # diag(P_C)
+    state_scr[...] = jax.lax.dot_general(
+        decay, s, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32) + jax.lax.dot_general(
         kP, vc, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     @pl.when(j == n_chunks - 1)
@@ -78,7 +91,7 @@ def _kernel(q_ref, k_ref, v_ref, lw_ref, s0_ref, u_ref, o_ref, sT_ref,
 
 
 def ssm_scan_pallas(q, k, v, log_w, state, u=None, *, chunk: int = 16,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q/k/lw: [BH, S, dk]; v: [BH, S, dv]; state: [BH, dk, dv] f32;
     u: [BH, dk] or None.  Returns (o [BH, S, dv], final_state)."""
     BH, S, dk = q.shape
@@ -89,6 +102,9 @@ def ssm_scan_pallas(q, k, v, log_w, state, u=None, *, chunk: int = 16,
     rwkv = u is not None
     if u is None:
         u = jnp.zeros((BH, dk), jnp.float32)
+    # [BH, 1, dk]: a (1, dk) block spans its array's last two dims, the
+    # only legal TPU tile for a row this narrow
+    u = u.reshape(BH, 1, dk)
 
     kern = functools.partial(_kernel, chunk=C, n_chunks=n, rwkv=rwkv)
     o, sT = pl.pallas_call(
@@ -100,7 +116,7 @@ def ssm_scan_pallas(q, k, v, log_w, state, u=None, *, chunk: int = 16,
             pl.BlockSpec((1, C, dv), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, C, dk), lambda b, j: (b, j, 0)),
             pl.BlockSpec((1, dk, dv), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, dk), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, dk), lambda b, j: (b, 0, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, C, dv), lambda b, j: (b, j, 0)),

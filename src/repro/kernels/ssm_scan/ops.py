@@ -24,7 +24,7 @@ def _scan(q, k, v, log_w, state, u, *, chunk, interpret):
 
 
 def ssm_scan(q, k, v, log_w, state, u=None, *, chunk: int = 16,
-             interpret: bool = True):
+             interpret: bool = False):
     """Same contract as ``repro.models.linear_scan.linear_scan``:
     q/k/log_w [B,S,H,dk]; v [B,S,H,dv]; state [B,H,dk,dv]; u [H,dk]|None."""
     return _scan(q, k, v, log_w, state, u, chunk=chunk, interpret=interpret)

@@ -8,26 +8,13 @@ import; smoke tests and benchmarks see the real single device.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:                                   # jax >= 0.5: explicit axis types
-    from jax.sharding import AxisType
-except ImportError:                    # older jax: meshes are Auto already
-    AxisType = None
-
-
-def mesh_axis_kwargs(num_axes: int) -> dict:
-    """``axis_types=`` kwarg for :func:`jax.make_mesh`, empty on jax
-    versions that predate ``AxisType`` (where Auto is the only behavior)."""
-    if AxisType is None:
-        return {}
-    return {"axis_types": (AxisType.Auto,) * num_axes}
+from jax.sharding import AxisType, Mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **mesh_axis_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1) -> Mesh:
@@ -35,7 +22,7 @@ def make_host_mesh(model_parallel: int = 1) -> Mesh:
     n = len(jax.devices())
     data = max(1, n // model_parallel)
     return jax.make_mesh((data, model_parallel), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def batch_axes(mesh: Mesh):

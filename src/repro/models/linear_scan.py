@@ -107,7 +107,7 @@ def scan_chunked(q, k, v, log_w, state, u=None, chunk: int = 16):
 def linear_scan(q, k, v, log_w, state, u=None, *, mode: str = "auto",
                 chunk: int = 16, use_kernel: bool = False):
     """Dispatch: sequential for short/decode, chunked for long sequences,
-    Pallas kernel when ``use_kernel`` (TPU target; interpret on CPU tests)."""
+    Pallas kernel when ``use_kernel`` (compiled for TPU)."""
     if use_kernel:
         from repro.kernels.ssm_scan import ops as ssm_ops
         return ssm_ops.ssm_scan(q, k, v, log_w, state, u=u, chunk=chunk)

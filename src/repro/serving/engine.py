@@ -470,18 +470,14 @@ class CoInferenceStepper:
         host has one (params replicated, the batch axis split).  On a
         single-device host — or a bucket the mesh doesn't divide — this is
         the identity: the plain vmap variant runs, bit-identically."""
+        from jax.sharding import Mesh, PartitionSpec as P
         devices = jax.devices()
         if len(devices) <= 1 or bucket % len(devices) != 0:
             return vstep
-        try:
-            from jax.experimental.shard_map import shard_map
-            from jax.sharding import Mesh, PartitionSpec as P
-        except ImportError:                                # pragma: no cover
-            return vstep
         mesh = Mesh(np.array(devices), ("b",))
-        return shard_map(vstep, mesh=mesh,
-                         in_specs=(P(), P("b"), P("b"), P("b")),
-                         out_specs=(P("b"), P("b")))
+        return jax.shard_map(vstep, mesh=mesh,
+                             in_specs=(P(), P("b"), P("b"), P("b")),
+                             out_specs=(P("b"), P("b")))
 
     def decode_fn_batched(self, graph_exit: Optional[int], batch: int, *,
                           sharded: bool = False):

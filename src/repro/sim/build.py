@@ -27,7 +27,8 @@ from repro.fleet.mobility import (HandoverController, MobilityModel,
 from repro.fleet.workload import FleetRequest, make_workload
 from repro.sim.spec import PlannerSpec, ScenarioSpec, TopologySpec
 
-__all__ = ["Scenario", "Simulation", "build_stack", "build_topology"]
+__all__ = ["Scenario", "Simulation", "build_stack", "build_topology",
+           "engine_dtype"]
 
 
 @dataclass
@@ -49,30 +50,54 @@ class Scenario:
     engine: Optional[FleetEngine] = None
 
 
+def engine_dtype(name: Optional[str]):
+    """The ``jax.numpy`` dtype an ``EngineSpec.dtype`` names (``None`` for
+    an unset name); anything that is not a dtype raises ``ValueError``."""
+    if name is None:
+        return None
+    import jax.numpy as jnp
+    import numpy as np
+    dtype = getattr(jnp, name, None)
+    try:
+        if dtype is None:
+            raise TypeError
+        np.dtype(dtype)
+    except TypeError:
+        raise ValueError(
+            f"unknown engine dtype {name!r}: expected a jax.numpy dtype "
+            "name such as 'float32' or 'bfloat16'") from None
+    return dtype
+
+
 def build_stack(spec: PlannerSpec, *, with_model: bool = False,
                 with_params: Optional[bool] = None,
                 scenario_spec: Optional[ScenarioSpec] = None) -> Scenario:
-    """Build the smoke-scale LM stack a spec's planner describes: config,
+    """Build the LM stack a spec's planner describes: config (the reduced
+    smoke preset, or the published one with ``spec.full_width``),
     ``InferenceGraph`` (input/result payloads applied), and an
     ``EdgentPlanner`` whose roofline predictors are rescaled to the spec's
-    per-tier step times.  ``with_model=True`` additionally constructs the
+    per-tier step times.  The planner graph and the executed model come
+    from the same config.  ``with_model=True`` additionally constructs the
     executable model; ``with_params`` (default: follows ``with_model``)
     controls whether its parameters are initialized — the expensive half
-    (fp32 params, fixed init key — part of the scenario contract, not the
-    seed tree).  Prompt-sampling-only scenarios need neither: the vocab
-    comes from ``cfg``, so they build with both off and skip model
-    construction entirely.
+    (fixed init key — part of the scenario contract, not the seed tree —
+    drawn in one compiled program directly in ``scenario_spec.engine
+    .dtype``, float32 when unset).
+    Prompt-sampling-only scenarios need neither: the vocab comes from
+    ``cfg``, so they build with both off and skip model construction
+    entirely.
 
     With ``scenario_spec.calibration`` set, the planner's latency models are
     replaced by regressions fitted from the named measured
     :class:`~repro.calib.CalibrationTable` (``repro.calib.fit`` — see
     docs/calibration.md)."""
-    from repro.configs import get_smoke_config
+    from repro.configs import get_config, get_smoke_config
     from repro.core import EdgentPlanner, lm_graph
     from repro.core.latency_model import (RooflineLatencyModel,
                                           ScaledLatencyModel)
 
-    cfg = get_smoke_config(spec.arch)
+    cfg = get_config(spec.arch) if spec.full_width \
+        else get_smoke_config(spec.arch)
     graph = lm_graph(cfg, batch=1, seq=1)
     graph.input_bytes = int(spec.input_kb * 1024)
     if spec.result_kb is not None:
@@ -105,7 +130,14 @@ def build_stack(spec: PlannerSpec, *, with_model: bool = False,
         from repro.models import Model
         model = Model(cfg)
         if with_params:
-            params = model.init_params(jax.random.key(0), dtype=jnp.float32)
+            dtype = None if scenario_spec is None else \
+                engine_dtype(scenario_spec.engine.dtype)
+            # one compiled program: each leaf is drawn and scaled in
+            # registers and stored once in `dtype` (eagerly, every leaf
+            # would first materialize in float32, and every shape would
+            # compile its own sampler)
+            params = jax.jit(model.init_params, static_argnums=1)(
+                jax.random.key(0), dtype or jnp.float32)
     return Scenario(spec=scenario_spec, cfg=cfg, graph=graph,
                     planner=planner, model=model, params=params)
 
@@ -193,20 +225,7 @@ class Simulation:
             tenants=w.tenants, device_skew=w.device_skew,
             peak_factor=w.peak_factor, period_s=w.period_s,
             prompt_len=w.prompt_len, vocab_size=vocab)
-        dtype = None
-        if spec.engine.dtype is not None:
-            import jax.numpy as jnp
-            import numpy as np
-            dtype = getattr(jnp, spec.engine.dtype, None)
-            try:
-                if dtype is None:
-                    raise TypeError
-                np.dtype(dtype)
-            except TypeError:
-                raise ValueError(
-                    f"unknown engine dtype {spec.engine.dtype!r}: expected "
-                    "a jax.numpy dtype name such as 'float32' or "
-                    "'bfloat16'") from None
+        dtype = engine_dtype(spec.engine.dtype)
         autoscaler = admission = None
         if spec.autoscale is not None or spec.admission is not None:
             from repro.fleet.elastic import build_elasticity

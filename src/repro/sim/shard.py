@@ -32,7 +32,7 @@ from repro.fleet.metrics import FleetMetrics
 from repro.fleet.mobility import HandoverController, make_mobile_fleet
 from repro.fleet.cluster import make_fleet
 from repro.fleet.workload import make_workload
-from repro.sim.build import build_stack
+from repro.sim.build import build_stack, engine_dtype
 from repro.sim.spec import ScenarioSpec
 
 __all__ = ["run_sharded", "run_sharded_info", "run_tile", "tile_spec"]
@@ -135,10 +135,7 @@ def run_tile(spec: ScenarioSpec, g: int) -> Tuple[FleetMetrics, Dict]:
         peak_factor=w.peak_factor, period_s=w.period_s,
         prompt_len=w.prompt_len, vocab_size=vocab,
         rid0=g * RID_STRIDE, did0=did0)
-    dtype = None
-    if tspec.engine.dtype is not None:
-        import jax.numpy as jnp
-        dtype = getattr(jnp, tspec.engine.dtype)
+    dtype = engine_dtype(tspec.engine.dtype)
     autoscaler = admission = None
     if tspec.autoscale is not None or tspec.admission is not None:
         from repro.fleet.elastic import build_elasticity
@@ -189,9 +186,12 @@ def run_sharded_info(spec: ScenarioSpec, *,
     infos: List[Optional[Dict]] = [None] * k
     if processes is not None and processes > 1:
         import multiprocessing as mp
+
+        from repro.sim.sweep import host_worker_init
         ctx = mp.get_context("spawn")
         payload = [json.dumps([spec.to_json(), g]) for g in range(k)]
-        with ctx.Pool(min(processes, k)) as pool:
+        with ctx.Pool(min(processes, k),
+                      initializer=host_worker_init) as pool:
             for g, (m, info) in enumerate(pool.imap(_run_tile_json,
                                                     payload)):
                 parts[g], infos[g] = m, info

@@ -246,15 +246,19 @@ class AdmissionSpec(_Spec):
 
 @dataclass
 class PlannerSpec(_Spec):
-    """The model stack the Edgent planner optimizes over: a smoke-scale LM
-    graph with roofline predictors rescaled so one device-only decode step
-    costs ``device_step_s`` and one edge step ``edge_step_s`` (the paper's
-    Fig. 2 tier asymmetry at per-token granularity).  ``input_kb`` is the
+    """The model stack the Edgent planner optimizes over: an LM graph with
+    roofline predictors rescaled so one device-only decode step costs
+    ``device_step_s`` and one edge step ``edge_step_s`` (the paper's
+    Fig. 2 tier asymmetry at per-token granularity).  ``arch`` names a
+    registry model, built as its reduced smoke preset or, with
+    ``full_width``, at its published widths — the planner graph and the
+    executed model always share that config.  ``input_kb`` is the
     offloaded prompt payload (multimodal-style image features);
     ``result_kb``, when set, adds a per-token downlink so streaming
     requests stay bandwidth-bound for their whole decode (the mobility
     scenarios rely on this)."""
     arch: str = "llama3.2-1b"
+    full_width: bool = False
     latency_req_s: float = 0.5
     input_kb: float = 24.0
     device_step_s: float = 0.06
@@ -281,11 +285,11 @@ class RouterSpec(_Spec):
 class EngineSpec(_Spec):
     """FleetEngine knobs: timing-only simulation by default;
     ``real_decode=True`` also runs the actual model (B=1 caches, jitted
-    per-exit variants) — ``dtype`` then names the cache dtype (e.g.
-    ``'float32'``, ``'bfloat16'``).  ``retain_records=False`` keeps
-    FleetMetrics to its running aggregates (identical summaries, no
-    per-request record/handover-log retention) — the 10k-device / sweep
-    setting (docs/performance.md).
+    per-exit variants) — ``dtype`` then names the parameter and cache
+    dtype (e.g. ``'float32'``, ``'bfloat16'``; float32 when unset).
+    ``retain_records=False`` keeps FleetMetrics to its running aggregates
+    (identical summaries, no per-request record/handover-log retention) —
+    the 10k-device / sweep setting (docs/performance.md).
 
     Observability (docs/observability.md): ``trace`` writes a
     Chrome/Perfetto trace-event JSON of every request's lifecycle spans to

@@ -122,6 +122,19 @@ def _run_cell_json(spec_json: str) -> Dict:
     return run_cell(ScenarioSpec.from_json(spec_json))
 
 
+def host_worker_init() -> None:
+    """Pool initializer: pin a host-only simulation worker to the CPU
+    backend.  An accelerator belongs to one process at a time, and sweep
+    and shard workers simulate on the host by design.  ``jax`` is already
+    imported when a spawned worker runs this, so the config update is what
+    takes effect; the variable covers processes the worker starts."""
+    import os
+
+    import jax
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    jax.config.update("jax_platforms", "cpu")
+
+
 def run_sweep(cells: Iterable[ScenarioSpec], *,
               out_path: Optional[str] = None,
               processes: Optional[int] = None,
@@ -132,8 +145,17 @@ def run_sweep(cells: Iterable[ScenarioSpec], *,
     ``processes`` > 1 fans cells out over a multiprocessing pool (specs
     travel as JSON, so workers rebuild them with the same strict
     validation); ``None`` or 1 runs inline.  ``out_path`` additionally
-    streams rows to a JSONL file as they arrive."""
+    streams rows to a JSONL file as they arrive.  Cells with
+    ``engine.real_decode`` execute the model, which must run in the one
+    process that holds the accelerator: pooling them raises
+    ``ValueError``."""
     cells = list(cells)
+    if processes is not None and processes > 1 and any(
+            c.engine.real_decode for c in cells):
+        raise ValueError(
+            "run_sweep(processes>1) does not support engine.real_decode "
+            "cells (each worker would build its own model replica and "
+            "contend for the one accelerator); run them with processes=1")
     rows: List[Optional[Dict]] = [None] * len(cells)
     out = open(out_path, "w") if out_path else None
 
@@ -152,7 +174,7 @@ def run_sweep(cells: Iterable[ScenarioSpec], *,
         if processes is not None and processes > 1 and len(cells) > 1:
             import multiprocessing as mp
             ctx = mp.get_context("spawn")  # no fork: jax/BLAS state unsafe
-            with ctx.Pool(processes) as pool:
+            with ctx.Pool(processes, initializer=host_worker_init) as pool:
                 payload = [c.to_json() for c in cells]
                 for i, row in enumerate(pool.imap(_run_cell_json, payload)):
                     emit(i, row)

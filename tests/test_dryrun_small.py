@@ -18,17 +18,17 @@ import jax, jax.numpy as jnp
 from repro.configs import get_smoke_config
 from repro.config import ShapeConfig
 from repro.models import Model
-from repro.launch.mesh import mesh_axis_kwargs
+from jax.sharding import AxisType
 from repro.launch.steps import make_step
 from repro.launch.dryrun import collective_stats
 
 arch, kind, multipod = "%(arch)s", "%(kind)s", %(multipod)s
 if multipod:
     mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
-                         **mesh_axis_kwargs(3))
+                         axis_types=(AxisType.Auto,) * 3)
 else:
     mesh = jax.make_mesh((2, 4), ("data", "model"),
-                         **mesh_axis_kwargs(2))
+                         axis_types=(AxisType.Auto,) * 2)
 cfg = get_smoke_config(arch)
 model = Model(cfg)
 shape = ShapeConfig("t", 64, 8, kind)
@@ -37,8 +37,6 @@ with mesh:
     lowered = step.lower(*abstract_inputs())
 compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, list):               # older jax: list of per-device dicts
-    ca = ca[0] if ca else {}
 coll = collective_stats(compiled.as_text())
 print(json.dumps({"flops": ca.get("flops", 0.0),
                   "coll": coll["total_link_bytes"],

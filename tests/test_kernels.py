@@ -1,5 +1,7 @@
-"""Per-kernel allclose sweeps against the pure-jnp oracles (interpret=True
-executes the Pallas kernel body on CPU)."""
+"""Per-kernel allclose sweeps against the pure-jnp oracles.  Every call
+passes ``interpret=True`` (the kernels default to compiled TPU code), which
+executes the Pallas kernel body on CPU; tests/test_tpu_compile.py compiles
+the same kernels for a v5e chip."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,7 +25,8 @@ def test_exit_head_sweep(B, S, D, V, dtype):
     ks = jax.random.split(jax.random.key(B * S + D + V), 2)
     h = jax.random.normal(ks[0], (B, S, D), dtype)
     emb = jax.random.normal(ks[1], (V, D), dtype)
-    got = eh_ops.exit_confidence(h, emb, tile_rows=8, tile_v=128)
+    got = eh_ops.exit_confidence(h, emb, tile_rows=8, tile_v=128,
+                                 interpret=True)
     # the kernel upcasts h/emb to f32 before the dot, so the oracle must do
     # the same — an einsum in bf16 rounds the logits and is the LESS precise
     # of the two, flipping argmax ties and drifting the entropy sum
@@ -42,7 +45,8 @@ def test_exit_head_confidence_semantics():
     D, V = 32, 500
     emb = jax.random.normal(jax.random.key(0), (V, D))
     h = 20.0 * emb[42][None, None, :]            # aligned with one row
-    got = eh_ops.exit_confidence(h, emb, tile_rows=8, tile_v=128)
+    got = eh_ops.exit_confidence(h, emb, tile_rows=8, tile_v=128,
+                                 interpret=True)
     assert int(got["token"][0, 0]) == 42
     assert float(got["conf"][0, 0]) > 0.9
     assert float(got["entropy"][0, 0]) < 0.5
@@ -58,7 +62,8 @@ def test_flash_attention_sweep(B, H, KV, S, hd, causal):
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, KV, hd), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, KV, hd), jnp.float32)
-    got = fa_ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    got = fa_ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                                 interpret=True)
     want = fa_ref.attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3), causal=causal
                             ).transpose(0, 2, 1, 3)
@@ -72,7 +77,8 @@ def test_flash_attention_bf16():
     q = jax.random.normal(ks[0], (B, S, H, hd), jnp.bfloat16)
     k = jax.random.normal(ks[1], (B, S, H, hd), jnp.bfloat16)
     v = jax.random.normal(ks[2], (B, S, H, hd), jnp.bfloat16)
-    got = fa_ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    got = fa_ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                                 interpret=True)
     want = fa_ref.attention(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -93,7 +99,8 @@ def test_decode_attention_sweep(B, H, KV, T, hd):
     lengths = jax.random.randint(ks[3], (B,), 1, T + 1)
     if B > 2:
         lengths = lengths.at[B - 1].set(0)     # an empty arena slot
-    got = fa_ops.decode_attention(q, k, v, lengths, block_k=64)
+    got = fa_ops.decode_attention(q, k, v, lengths, block_k=64,
+                                  interpret=True)
     want = fa_ref.decode_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), lengths).transpose(0, 2, 1, 3)
@@ -108,7 +115,8 @@ def test_decode_attention_bf16():
     k = jax.random.normal(ks[1], (B, T, H, hd), jnp.bfloat16)
     v = jax.random.normal(ks[2], (B, T, H, hd), jnp.bfloat16)
     lengths = jnp.asarray([7, 128], jnp.int32)
-    got = fa_ops.decode_attention(q, k, v, lengths, block_k=64)
+    got = fa_ops.decode_attention(q, k, v, lengths, block_k=64,
+                                  interpret=True)
     want = fa_ref.decode_attention(
         q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
         v.transpose(0, 2, 1, 3), lengths).transpose(0, 2, 1, 3)
@@ -130,7 +138,8 @@ def test_decode_attention_matches_causal_last_row():
                             k.transpose(0, 2, 1, 3),
                             v.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
     got = fa_ops.decode_attention(q_full[:, -1:], k, v,
-                                  jnp.asarray([T], jnp.int32), block_k=32)
+                                  jnp.asarray([T], jnp.int32), block_k=32,
+                                  interpret=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(full[:, -1:]),
                                rtol=2e-5, atol=2e-5)
 
@@ -148,7 +157,8 @@ def test_ssm_scan_sweep(B, S, H, dk, dv, rwkv):
     lw = -jnp.exp(jax.random.normal(ks[3], (B, S, H, dk)) * 0.5)
     st0 = jax.random.normal(ks[4], (B, H, dk, dv)) * 0.1
     u = jax.random.normal(ks[5], (H, dk)) * 0.1 if rwkv else None
-    o1, s1 = ss_ops.ssm_scan(q, k, v, lw, st0, u=u, chunk=16)
+    o1, s1 = ss_ops.ssm_scan(q, k, v, lw, st0, u=u, chunk=16,
+                              interpret=True)
     o2, s2 = ss_ref.ssm_scan(q, k, v, lw, st0, u=u)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=3e-4, atol=3e-4)
     np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=3e-4, atol=3e-4)
@@ -167,6 +177,7 @@ def test_property_ssm_scan(seed, nchunk, rwkv, chunk):
     lw = -jnp.exp(jax.random.normal(ks[3], (B, S, H, dk)) * 0.5)
     st0 = jnp.zeros((B, H, dk, dv))
     u = jax.random.normal(ks[5], (H, dk)) * 0.1 if rwkv else None
-    o1, s1 = ss_ops.ssm_scan(q, k, v, lw, st0, u=u, chunk=chunk)
+    o1, s1 = ss_ops.ssm_scan(q, k, v, lw, st0, u=u, chunk=chunk,
+                              interpret=True)
     o2, s2 = ss_ref.ssm_scan(q, k, v, lw, st0, u=u)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), rtol=5e-4, atol=5e-4)

@@ -126,8 +126,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, json
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_cost import walk_costs
-from repro.launch.mesh import mesh_axis_kwargs
-mesh = jax.make_mesh((2,4), ("data","model"), **mesh_axis_kwargs(2))
+from jax.sharding import AxisType
+mesh = jax.make_mesh((2,4), ("data","model"), axis_types=(AxisType.Auto,) * 2)
 x_sh = NamedSharding(mesh, P("data", None))
 w_sh = NamedSharding(mesh, P("data","model"))
 def scanned(x, ws):

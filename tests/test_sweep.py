@@ -66,6 +66,15 @@ def test_run_sweep_parallel_matches_inline():
     assert canon(inline) == canon(pooled)
 
 
+def test_run_sweep_refuses_pooled_real_decode():
+    """Real decode runs the model, which belongs in the one process that
+    holds the accelerator: a pool of workers must refuse it up front."""
+    cells = grid_cells(_base(), {"seed": [0, 1]})
+    cells[1] = apply_overrides(cells[1], {"engine.real_decode": True})
+    with pytest.raises(ValueError, match="real_decode"):
+        run_sweep(cells, processes=2)
+
+
 def test_sweep_cell_reproduces_fleet_scale_table_cells():
     """The --coop / --mobility benchmark tables are sweeps now; their cells
     must equal a direct Simulation of the registered scenario (the pinned
