@@ -29,7 +29,6 @@ without dropping or double-counting it.
 from __future__ import annotations
 
 import heapq
-import time
 from typing import List, Optional, Union
 
 import numpy as np
@@ -165,6 +164,7 @@ class FleetEngine:
         self.events_processed = 0
         self.event_counts = {}
         self.enqueued = self.tombstoned = 0
+        self._spans = None
 
     # ---------------------------------------------------------------- run
     def run(self, workload: List[FleetRequest]) -> FleetMetrics:
@@ -256,6 +256,8 @@ class FleetEngine:
         prof = self.profiler
         if prof is not None:
             prof.reset()
+        # the real-decode path's host spans and counters (None: off)
+        self._spans = prof if self.model is not None else None
         self.events_processed = 0          # sweeps count once per device
         self.event_counts = {}             # heap pops by event kind
         self.enqueued = self.tombstoned = 0
@@ -264,27 +266,11 @@ class FleetEngine:
             self.events_processed += 1
             kind = ev.kind
             self.event_counts[kind] = self.event_counts.get(kind, 0) + 1
-            if prof is not None:
-                t0 = time.perf_counter()
-            if kind == "arrival":
-                self._on_arrival(ev.payload, evq, metrics)
-            elif kind == "round":
-                self._on_round_done(ev.payload, evq, metrics)
-            elif kind == "local_done":
-                self._on_local_done(ev.payload, evq, metrics)
-            elif kind == "transfer":
-                src, dst, nbytes = ev.payload
-                metrics.add_transfer(src, dst, nbytes)
-            elif kind == "sample":
-                self._on_sample_sweep(evq, metrics)
-            elif kind == "handover":
-                self._on_handover(ev.payload, evq, metrics)
-            elif kind == "scale":
-                self._on_scale(evq, metrics)
-            elif kind == "obs":
-                self._on_obs(evq)
-            if prof is not None:
-                prof.add(kind, time.perf_counter() - t0, len(evq))
+            if prof is None:
+                self._dispatch(kind, ev.payload, evq, metrics)
+            else:
+                with prof.event(kind, evq):
+                    self._dispatch(kind, ev.payload, evq, metrics)
         if elastic:
             metrics.finalize_capacity()
         if self.tracer is not None and self.model is not None:
@@ -297,6 +283,26 @@ class FleetEngine:
                                       "arena": st["arena"],
                                       "jit": st["jit"]})
         return metrics
+
+    def _dispatch(self, kind: str, payload, evq: EventQueue,
+                  metrics: FleetMetrics):
+        if kind == "arrival":
+            self._on_arrival(payload, evq, metrics)
+        elif kind == "round":
+            self._on_round_done(payload, evq, metrics)
+        elif kind == "local_done":
+            self._on_local_done(payload, evq, metrics)
+        elif kind == "transfer":
+            src, dst, nbytes = payload
+            metrics.add_transfer(src, dst, nbytes)
+        elif kind == "sample":
+            self._on_sample_sweep(evq, metrics)
+        elif kind == "handover":
+            self._on_handover(payload, evq, metrics)
+        elif kind == "scale":
+            self._on_scale(evq, metrics)
+        elif kind == "obs":
+            self._on_obs(evq)
 
     # ------------------------------------------------------------ bandwidth
     def _bw(self, device, eid: int, now: float) -> float:
@@ -367,6 +373,8 @@ class FleetEngine:
             tr.async_begin("queue", req.rid, evq.now, tr.PID_DEVICES,
                            req.device, args={"edge": edge.eid})
         self._enqueue(edge, req)
+        if self._spans is not None:
+            self._spans.arrived(req.rid)
         edge.tokens_owed += req.max_new_tokens
         self._dev_inflight[req.device].append(req)
         if not edge.round_inflight:
@@ -489,6 +497,17 @@ class FleetEngine:
 
     def _on_round_done(self, edge: EdgeNode, evq: EventQueue,
                        metrics: FleetMetrics):
+        if self._spans is None:
+            self._retire(edge, evq, metrics)
+        else:
+            with self._spans.span("fleet.retire"):
+                self._retire(edge, evq, metrics)
+        self._begin_round(edge, evq, metrics)
+
+    def _retire(self, edge: EdgeNode, evq: EventQueue,
+                metrics: FleetMetrics):
+        """Round boundary: count the round's token, retire finished
+        requests (evicting their arena rows), execute pending replans."""
         now = evq.now
         still_active = []
         for req in edge.active:
@@ -548,12 +567,23 @@ class FleetEngine:
                     self._set_capacity(edge, cap, now, metrics)
                 if cap == tgt:
                     del self._cap_target[edge.eid]
-        self._begin_round(edge, evq, metrics)
 
     # ---------------------------------------------------------------- rounds
     def _begin_round(self, edge: EdgeNode, evq: EventQueue,
                      metrics: FleetMetrics):
+        if self._spans is None:
+            self._round(edge, evq, metrics)
+        else:
+            with self._spans.span("fleet.round", edge=edge.eid,
+                                  active=len(edge.active)):
+                self._round(edge, evq, metrics)
+
+    def _round(self, edge: EdgeNode, evq: EventQueue,
+               metrics: FleetMetrics):
+        """Admit from the EDF queue, price the round, decode its tokens
+        and schedule its end."""
         now = evq.now
+        spans = self._spans
         # admit in EDF order up to the batch width (continuous batching:
         # this happens at every round boundary, not at batch completion).
         # While a scale-down is draining, admission is capped at the drain
@@ -580,24 +610,71 @@ class FleetEngine:
                     self.topo.edge(eid).coop_inflight += 1
                 req.coop_counted = True
             if self.model is not None:
-                if self.arena_decode:
-                    # slot-resident path: prefill (or a migrated request's
-                    # shipped cache) scatters into the edge arena once here;
-                    # the request stays resident until completion/extract
-                    ar = self._arena(edge)
-                    if not ar.has(req.rid):
-                        if req.cache is None:
-                            self._prefill_real(req)
-                        ar.admit(req.rid, req.cache)
-                        req.cache = None   # state lives in the arena now
-                elif req.cache is None:
-                    # migrated requests keep their shipped cache —
-                    # re-prefilling would clobber the decode state the
-                    # handover paid to move
-                    self._prefill_real(req)
+                if spans is None:
+                    self._admit_real(edge, req)
+                else:
+                    with spans.span("fleet.admit", rid=req.rid):
+                        spans.admitted(req.rid)
+                        self._admit_real(edge, req)
             edge.active.append(req)
         if not edge.active:
             return
+        if spans is None:
+            round_dt, decode_batch = self._price(edge, now, evq, metrics)
+        else:
+            with spans.span("fleet.price"):
+                round_dt, decode_batch = self._price(edge, now, evq, metrics)
+        if decode_batch:
+            if self.arena_decode:
+                self._decode_real_arena(edge, decode_batch)
+            else:
+                self._decode_real_batch(decode_batch)
+        edge.busy_s += round_dt
+        metrics.add_busy(edge.eid, round_dt)
+        edge.ema_round_s = round_dt if edge.ema_round_s == 0.0 else \
+            0.8 * edge.ema_round_s + 0.2 * round_dt
+        edge.round_inflight = True
+        tr = self.tracer
+        if tr is not None:
+            eid = edge.eid
+            tr.complete("round", now, now + round_dt, eid, 0,
+                        args={"batch": len(edge.active)})
+            tr.counter("backlog_s", now, eid,
+                       {"backlog_s": edge.backlog_s()})
+            tr.counter("slots", now, eid,
+                       {"active": len(edge.active),
+                        "queued": len(edge.queue) - edge.q_dead})
+            tr.counter("tokens_owed", now, eid,
+                       {"tokens_owed": edge.tokens_owed})
+            tr.counter("coop_inflight", now, eid,
+                       {"coop_inflight": edge.coop_inflight})
+        evq.push(now + round_dt, "round", edge)
+
+    def _admit_real(self, edge: EdgeNode, req: FleetRequest):
+        """Give an admitted request its decode state on this edge."""
+        if self.arena_decode:
+            # slot-resident path: prefill (or a migrated request's shipped
+            # cache) scatters into the edge arena once here; the request
+            # stays resident until completion/extract
+            ar = self._arena(edge)
+            if not ar.has(req.rid):
+                if req.cache is None:
+                    self._prefill_real(req)
+                if self._spans is None:
+                    ar.admit(req.rid, req.cache)
+                else:
+                    with self._spans.span("arena.scatter"):
+                        ar.admit(req.rid, req.cache)
+                req.cache = None       # state lives in the arena now
+        elif req.cache is None:
+            # migrated requests keep their shipped cache — re-prefilling
+            # would clobber the decode state the handover paid to move
+            self._prefill_real(req)
+
+    def _price(self, edge: EdgeNode, now: float, evq: EventQueue,
+               metrics: FleetMetrics):
+        """Plan, per-exit prices and exit choice of every active request:
+        the round's virtual length and its real-decode group."""
         tr = self.tracer
         round_dt = 0.0
         decode_batch = []          # this round's real-decode group
@@ -659,30 +736,7 @@ class FleetEngine:
                 # already fixed, so collecting first changes nothing)
                 decode_batch.append(req)
             round_dt = max(round_dt, t_step)
-        if decode_batch:
-            if self.arena_decode:
-                self._decode_real_arena(edge, decode_batch)
-            else:
-                self._decode_real_batch(decode_batch)
-        edge.busy_s += round_dt
-        metrics.add_busy(edge.eid, round_dt)
-        edge.ema_round_s = round_dt if edge.ema_round_s == 0.0 else \
-            0.8 * edge.ema_round_s + 0.2 * round_dt
-        edge.round_inflight = True
-        if tr is not None:
-            eid = edge.eid
-            tr.complete("round", now, now + round_dt, eid, 0,
-                        args={"batch": len(edge.active)})
-            tr.counter("backlog_s", now, eid,
-                       {"backlog_s": edge.backlog_s()})
-            tr.counter("slots", now, eid,
-                       {"active": len(edge.active),
-                        "queued": len(edge.queue) - edge.q_dead})
-            tr.counter("tokens_owed", now, eid,
-                       {"tokens_owed": edge.tokens_owed})
-            tr.counter("coop_inflight", now, eid,
-                       {"coop_inflight": edge.coop_inflight})
-        evq.push(now + round_dt, "round", edge)
+        return round_dt, decode_batch
 
     # ---------------------------------------------------------------- coop
     def _emit_hops(self, req: FleetRequest, now: float, evq: EventQueue,
@@ -1030,6 +1084,13 @@ class FleetEngine:
 
     # ---------------------------------------------------------------- real decode
     def _prefill_real(self, req: FleetRequest):
+        if self._spans is None:
+            self._prefill_body(req)
+        else:
+            with self._spans.span("fleet.prefill"):
+                self._prefill_body(req)
+
+    def _prefill_body(self, req: FleetRequest):
         import jax.numpy as jnp
         assert req.prompt is not None, \
             "real-decode fleet needs prompts (make_workload(vocab_size=...))"
@@ -1052,6 +1113,8 @@ class FleetEngine:
         logits = self.model.logits(self.params, h)
         req.next_tok = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)[:, None]
         req.tokens.append(int(req.next_tok[0, 0]))
+        if self._spans is not None:
+            self._spans.host_reads += 1
 
     def _decode_real_batch(self, reqs: List[FleetRequest]):
         """One decode round's token step for every active request at an
@@ -1074,6 +1137,8 @@ class FleetEngine:
             req.next_tok = jnp.argmax(logits[:, -1, :], -1) \
                 .astype(jnp.int32)[:, None]
             req.tokens.append(int(req.next_tok[0, 0]))
+            if self._spans is not None:
+                self._spans.host_reads += 1
 
     def _arena(self, edge: EdgeNode):
         """The edge's decode arena, created lazily at first admission:
@@ -1098,17 +1163,31 @@ class FleetEngine:
         restacking, then one batched logits/argmax per exit group — the
         head is row-independent, so each request's token is bit-identical
         to the serial per-request epilogue."""
-        import jax.numpy as jnp
         ar = self._arenas[edge.eid]
         items = [(req.exit_point, ar.slot(req.rid), req.next_tok,
                   req.prompt_len + req.tokens_done) for req in reqs]
+        outs = self.stepper.decode_step_arena(self.params, ar, items,
+                                              profiler=self._spans)
+        if self._spans is None:
+            self._arena_epilogue(ar, reqs, outs)
+        else:
+            with self._spans.span("fleet.epilogue"):
+                self._arena_epilogue(ar, reqs, outs)
+
+    def _arena_epilogue(self, ar, reqs: List[FleetRequest], outs):
+        import jax.numpy as jnp
+        spans = self._spans
         next_toks = {}
-        for rows, h_all in self.stepper.decode_step_arena(
-                self.params, ar, items):
+        for rows, h_all in outs:
             logits = self.model.logits(self.params, h_all[:, 0])
             toks = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)
             for _, slot, _, _ in rows:
                 next_toks[slot] = toks[slot][None, None]
         for req in reqs:
             req.next_tok = next_toks[ar.slot(req.rid)]
-            req.tokens.append(int(req.next_tok[0, 0]))
+            if spans is None:
+                req.tokens.append(int(req.next_tok[0, 0]))
+            else:
+                with spans.span("fleet.emit"):
+                    req.tokens.append(int(req.next_tok[0, 0]))
+                    spans.host_reads += 1

@@ -602,8 +602,8 @@ class CoInferenceStepper:
         self._decode_ajit[key] = fn
         return fn
 
-    def decode_step_arena(self, params, arena, items: Sequence[tuple]
-                          ) -> List[tuple]:
+    def decode_step_arena(self, params, arena, items: Sequence[tuple], *,
+                          profiler=None) -> List[tuple]:
         """One decode step for every active slot of ``arena`` in at most
         one compiled call per model exit.
 
@@ -617,7 +617,12 @@ class CoInferenceStepper:
         full ``[slots, 1, 1, d]`` stack — callers index it by slot (each
         row bit-identical to the serial path) or, cheaper, feed it whole
         to one batched logits/argmax epilogue per group instead of one
-        per request (row-wise bit-identical on every backend we pin)."""
+        per request (row-wise bit-identical on every backend we pin).
+
+        ``profiler`` (a ``repro.obs.SimProfiler`` or ``None``) gets spans
+        ``arena.inputs`` (building the call's inputs, one blocking read of
+        each row's token, counted in ``host_reads``) and
+        ``arena.dispatch`` (the compiled call's dispatch)."""
         slots = arena.slots
         groups: "OrderedDict[Optional[int], List[tuple]]" = OrderedDict()
         for gexit, slot, tok, pos in items:
@@ -625,22 +630,36 @@ class CoInferenceStepper:
             groups.setdefault(mexit, []).append((gexit, slot, tok, pos))
         out: List[tuple] = []
         for rows in groups.values():
-            tok_a = np.zeros((slots, 1, 1), np.int32)
-            pos_a = np.zeros((slots,), np.int32)
-            mask_a = np.zeros((slots,), bool)
-            for _, slot, tok, pos in rows:
-                tok_a[slot] = np.asarray(tok, np.int32)
-                pos_a[slot] = pos
-                mask_a[slot] = True
+            if profiler is None:
+                args = self._arena_inputs(slots, rows)
+            else:
+                with profiler.span("arena.inputs"):
+                    args = self._arena_inputs(slots, rows)
+                    profiler.host_reads += len(rows)
             fn = self.decode_fn_arena(rows[0][0], arena)
-            h_all, arena.cache = fn(params, arena.cache,
-                                    jnp.asarray(tok_a), jnp.asarray(pos_a),
-                                    jnp.asarray(mask_a))
+            if profiler is None:
+                h_all, arena.cache = fn(params, arena.cache, *args)
+            else:
+                with profiler.span("arena.dispatch"):
+                    h_all, arena.cache = fn(params, arena.cache, *args)
             out.append((rows, h_all))
             self.arena_calls += 1
             self.arena_tokens += len(rows)
             self.arena_masked_rows += slots - len(rows)
         return out
+
+    @staticmethod
+    def _arena_inputs(slots: int, rows: List[tuple]) -> tuple:
+        """The masked arena call's token, position and mask arrays: token
+        0 at position 0 outside the mask."""
+        tok_a = np.zeros((slots, 1, 1), np.int32)
+        pos_a = np.zeros((slots,), np.int32)
+        mask_a = np.zeros((slots,), bool)
+        for _, slot, tok, pos in rows:
+            tok_a[slot] = np.asarray(tok, np.int32)
+            pos_a[slot] = pos
+            mask_a[slot] = True
+        return jnp.asarray(tok_a), jnp.asarray(pos_a), jnp.asarray(mask_a)
 
 
 class ServingEngine:

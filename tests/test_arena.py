@@ -293,6 +293,31 @@ def test_fleet_arena_equals_serial_static():
     assert 0 < st_["jit"]["variants"]["arena"] <= n_model
 
 
+def test_fleet_arena_profiler_neutral_and_counts_host_reads():
+    """A profiler on the real-decode arena path changes no token and no
+    summary; it counts two blocking host reads per arena token (the
+    token fed to the next step, and its emission) plus one per serially
+    decoded token, and one non-negative queue wait per admission."""
+    from repro.obs import SimProfiler
+    sc = Simulation(_static_spec(True)).build()
+    base = sc.engine.run(sc.workload).summary()
+    toks = {r.rid: list(r.tokens) for r in sc.workload}
+    st0 = sc.engine.stepper.cache_stats()
+    sc.engine.profiler = prof = SimProfiler()
+    assert sc.engine.run(sc.workload).summary() == base
+    assert {r.rid: list(r.tokens) for r in sc.workload} == toks
+    st1 = sc.engine.stepper.cache_stats()
+    arena_tokens = st1["arena"]["tokens"] - st0["arena"]["tokens"]
+    serial = st1["decode"]["serial_tokens"] - st0["decode"]["serial_tokens"]
+    assert arena_tokens > 0
+    assert prof.host_reads == 2 * arena_tokens + serial
+    assert sum(map(len, toks.values())) == arena_tokens + serial
+    waits = prof.counters()["queue_waits"]
+    assert len(waits) == st1["arena"]["admits"] - st0["arena"]["admits"]
+    assert all(w >= 0.0 for _, w in waits)
+    assert [t for t, _ in waits] == sorted(t for t, _ in waits)
+
+
 def _mobile_spec(arena: bool) -> ScenarioSpec:
     from repro.fleet.workload import TenantClass
     base = get_scenario("smoke-mobility")

@@ -375,6 +375,133 @@ def test_profiler_reset_keeps_build_s():
     assert prof.report()["build_s"] == 1.25
 
 
+# ------------------------------------------- host spans on the device clock
+#: each span of the real-decode path and the enclosing spans it may have
+#: (``None``: no enclosing program span); a device-tier request prefills
+#: inside its arrival's dispatch, an edge request inside its admission
+SPAN_PARENTS = {
+    "fleet.event.arrival": {None}, "fleet.event.round": {None},
+    "fleet.round": {"fleet.event.round", "fleet.event.arrival"},
+    "fleet.admit": {"fleet.round"},
+    "fleet.prefill": {"fleet.admit", "fleet.event.arrival"},
+    "arena.scatter": {"fleet.admit"},
+    "fleet.price": {"fleet.round"},
+    "arena.inputs": {"fleet.round"}, "arena.dispatch": {"fleet.round"},
+    "fleet.epilogue": {"fleet.round"}, "fleet.emit": {"fleet.epilogue"},
+    "fleet.retire": {"fleet.event.round"},
+}
+
+
+def _real_arena_spec():
+    from repro.fleet.workload import TenantClass
+    from repro.sim import EngineSpec
+    return ScenarioSpec(
+        name="obs-real-arena", seed=5,
+        topology=TopologySpec(num_devices=6, num_edges=1, trace="lte",
+                              edge_capacity=4),
+        workload=WorkloadSpec(rate_hz=8.0, horizon_s=1.5, prompt_len=6,
+                              tenants=(TenantClass("chat", slo_s=2.0,
+                                                   max_new_tokens=4,
+                                                   weight=1.0),)),
+        engine=EngineSpec(real_decode=True, arena_decode=True))
+
+
+def _program_spans(xplane):
+    """``[(name, start_ns, end_ns, parent)]`` of the program's spans on
+    the host plane's lines; the parent is the innermost enclosing program
+    span on the same line."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            evs = sorted(((e.name, e.start_ns, e.start_ns + e.duration_ns)
+                          for e in line.events
+                          if e.name.startswith(("fleet.", "arena."))),
+                         key=lambda ev: (ev[1], -ev[2]))
+            stack = []
+            for name, s, e in evs:
+                while stack and stack[-1][2] <= s:
+                    stack.pop()
+                out.append((name, s, e, stack[-1][0] if stack else None))
+                stack.append((name, s, e))
+    return out
+
+
+def test_host_spans_land_on_the_device_trace_with_their_parents(tmp_path):
+    import jax
+    sc = Simulation(_real_arena_spec()).build()
+    sc.engine.run(sc.workload)                   # compile outside the trace
+    sc.engine.profiler = prof = SimProfiler()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        sc.engine.run(sc.workload)
+    xplane, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    spans = _program_spans(xplane)
+    seen = {name for name, *_ in spans}
+    assert set(SPAN_PARENTS) <= seen
+    for name, s, e, parent in spans:
+        if name.startswith("fleet.event."):
+            assert parent is None, name
+        else:
+            assert parent in SPAN_PARENTS[name], (name, parent)
+    # the trace and the profiler's own table count the same spans
+    counts = {}
+    for name, *_ in spans:
+        counts[name] = counts.get(name, 0) + 1
+    rep = prof.report(sc.engine)
+    for name, block in rep["spans"].items():
+        assert counts[name] == block["count"], name
+    for kind, block in rep["events"].items():
+        assert counts["fleet.event." + kind] == block["count"], kind
+
+
+def test_profiler_off_enters_no_span(monkeypatch):
+    """With ``engine.profiler`` None no annotation is ever made; the
+    same run with a profiler attached does make them."""
+    import repro.obs.profile as profile_mod
+
+    class Refused:
+        def __init__(self, *a, **k):
+            raise AssertionError("span code ran with the profiler off")
+
+    sc = Simulation(_real_arena_spec()).build()
+    base = sc.engine.run(sc.workload).summary()
+    monkeypatch.setattr(profile_mod, "TraceAnnotation", Refused)
+    assert sc.engine.profiler is None
+    assert sc.engine.run(sc.workload).summary() == base
+    sc.engine.profiler = SimProfiler()
+    with pytest.raises(AssertionError, match="profiler off"):
+        sc.engine.run(sc.workload)
+
+
+def test_profiler_span_and_counters_unit():
+    prof = SimProfiler()
+    with prof.span("fleet.round", edge=0, active=2):
+        with prof.span("fleet.emit"):
+            pass
+    prof.arrived(7)
+    prof.admitted(7)
+    prof.admitted(8)                     # a re-admission: no arrival
+    prof.host_reads += 3
+    rep = prof.report()
+    assert rep["spans"]["fleet.round"]["count"] == 1
+    assert rep["spans"]["fleet.round"]["wall_s"] >= \
+        rep["spans"]["fleet.emit"]["wall_s"]
+    c = prof.counters()
+    assert c["host_reads"] == 3 and len(c["queue_waits"]) == 1
+    assert c["queue_waits"][0][1] >= 0.0
+    with pytest.raises(KeyError):          # an exception unwinds cleanly
+        with prof.span("fleet.admit"):
+            raise KeyError
+    assert prof.span_count["fleet.admit"] == 1
+    prof.reset()
+    assert prof.counters() == {"host_reads": 0, "queue_waits": []}
+    assert "spans" not in prof.report()
+
+
 # ---------------------------------------------------------------------- CLI
 
 
