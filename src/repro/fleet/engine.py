@@ -1160,9 +1160,9 @@ class FleetEngine:
         """One decode round's token step through the edge's slot-resident
         arena: at most one masked compiled call per model exit
         (``CoInferenceStepper.decode_step_arena``) with no per-round cache
-        restacking, then one batched logits/argmax per exit group — the
-        head is row-independent, so each request's token is bit-identical
-        to the serial per-request epilogue."""
+        restacking, then one batched logits/argmax per exit group, read
+        to the host once — the head is row-independent, so each request's
+        token is bit-identical to the serial per-request epilogue."""
         ar = self._arenas[edge.eid]
         items = [(req.exit_point, ar.slot(req.rid), req.next_tok,
                   req.prompt_len + req.tokens_done) for req in reqs]
@@ -1175,19 +1175,25 @@ class FleetEngine:
                 self._arena_epilogue(ar, reqs, outs)
 
     def _arena_epilogue(self, ar, reqs: List[FleetRequest], outs):
+        """Each exit group's ``[slots]`` token vector crosses to the host
+        in one read; every row's ``next_tok`` becomes a ``(1, 1)`` int32
+        host array cut from its group's vector, so neither the emit nor
+        the next step's inputs wait on the device again."""
         import jax.numpy as jnp
         spans = self._spans
         next_toks = {}
         for rows, h_all in outs:
             logits = self.model.logits(self.params, h_all[:, 0])
-            toks = jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32)
+            toks = np.asarray(
+                jnp.argmax(logits[:, -1, :], -1).astype(jnp.int32))
+            if spans is not None:
+                spans.host_reads += 1
             for _, slot, _, _ in rows:
-                next_toks[slot] = toks[slot][None, None]
+                next_toks[slot] = toks[slot:slot + 1, None]
         for req in reqs:
-            req.next_tok = next_toks[ar.slot(req.rid)]
+            req.next_tok = tok = next_toks[ar.slot(req.rid)]
             if spans is None:
-                req.tokens.append(int(req.next_tok[0, 0]))
+                req.tokens.append(int(tok[0, 0]))
             else:
                 with spans.span("fleet.emit"):
-                    req.tokens.append(int(req.next_tok[0, 0]))
-                    spans.host_reads += 1
+                    req.tokens.append(int(tok[0, 0]))

@@ -620,9 +620,12 @@ class CoInferenceStepper:
         per request (row-wise bit-identical on every backend we pin).
 
         ``profiler`` (a ``repro.obs.SimProfiler`` or ``None``) gets spans
-        ``arena.inputs`` (building the call's inputs, one blocking read of
-        each row's token, counted in ``host_reads``) and
-        ``arena.dispatch`` (the compiled call's dispatch)."""
+        ``arena.inputs`` (building the call's inputs) and
+        ``arena.dispatch`` (the compiled call's dispatch).  A row's token
+        that is already a host ``np.ndarray`` (what the fleet's epilogue
+        hands back) is copied in with no transfer; one still on the device
+        (a prefill's token, once per admission) costs a blocking read,
+        counted in ``host_reads``."""
         slots = arena.slots
         groups: "OrderedDict[Optional[int], List[tuple]]" = OrderedDict()
         for gexit, slot, tok, pos in items:
@@ -635,7 +638,9 @@ class CoInferenceStepper:
             else:
                 with profiler.span("arena.inputs"):
                     args = self._arena_inputs(slots, rows)
-                    profiler.host_reads += len(rows)
+                    profiler.host_reads += sum(
+                        not isinstance(tok, np.ndarray)
+                        for _, _, tok, _ in rows)
             fn = self.decode_fn_arena(rows[0][0], arena)
             if profiler is None:
                 h_all, arena.cache = fn(params, arena.cache, *args)

@@ -295,9 +295,18 @@ def test_fleet_arena_equals_serial_static():
 
 def test_fleet_arena_profiler_neutral_and_counts_host_reads():
     """A profiler on the real-decode arena path changes no token and no
-    summary; it counts two blocking host reads per arena token (the
-    token fed to the next step, and its emission) plus one per serially
-    decoded token, and one non-negative queue wait per admission."""
+    summary, and counts every blocking device-to-host read:
+
+    * the epilogue reads each exit group's token vector once: one read
+      per compiled arena call (``arena calls``);
+    * the step's inputs read a token only while it is still on the
+      device, which is the prefill's token at a request's first arena
+      round: one read per admission (this scenario hands nothing over,
+      so every admission carries a fresh prefill's token);
+    * the serial path reads each token it decodes once.
+
+    So ``host_reads == arena calls + admissions + serial tokens``; and
+    the profiler records one non-negative queue wait per admission."""
     from repro.obs import SimProfiler
     sc = Simulation(_static_spec(True)).build()
     base = sc.engine.run(sc.workload).summary()
@@ -308,14 +317,75 @@ def test_fleet_arena_profiler_neutral_and_counts_host_reads():
     assert {r.rid: list(r.tokens) for r in sc.workload} == toks
     st1 = sc.engine.stepper.cache_stats()
     arena_tokens = st1["arena"]["tokens"] - st0["arena"]["tokens"]
+    arena_calls = st1["arena"]["calls"] - st0["arena"]["calls"]
+    admits = st1["arena"]["admits"] - st0["arena"]["admits"]
     serial = st1["decode"]["serial_tokens"] - st0["decode"]["serial_tokens"]
-    assert arena_tokens > 0
-    assert prof.host_reads == 2 * arena_tokens + serial
+    assert arena_tokens > arena_calls > 0
+    assert prof.host_reads == arena_calls + admits + serial
     assert sum(map(len, toks.values())) == arena_tokens + serial
     waits = prof.counters()["queue_waits"]
-    assert len(waits) == st1["arena"]["admits"] - st0["arena"]["admits"]
+    assert len(waits) == admits
     assert all(w >= 0.0 for _, w in waits)
     assert [t for t, _ in waits] == sorted(t for t, _ in waits)
+
+
+def _demote_spec(arena: bool) -> ScenarioSpec:
+    """One edge, a tight and a loose tenant, deadline demotion on: the
+    tight tenant's requests demote to earlier exits while the loose ones
+    keep theirs, so arena rounds sweep one to three exit groups."""
+    from repro.fleet.workload import TenantClass
+    tenants = (TenantClass("tight", slo_s=0.1, max_new_tokens=8,
+                           weight=0.5),
+               TenantClass("loose", slo_s=4.0, max_new_tokens=8,
+                           weight=0.5))
+    return ScenarioSpec(
+        name="arena-demote", seed=3,
+        topology=TopologySpec(num_devices=8, num_edges=1, trace="lte",
+                              edge_capacity=8, max_edge_slowdown=2.0),
+        workload=WorkloadSpec(rate_hz=20.0, horizon_s=2.0, device_skew=0.5,
+                              prompt_len=6, tenants=tenants),
+        router=RouterSpec(name="bandwidth-aware"),
+        engine=EngineSpec(real_decode=True, batch_decode=False,
+                          arena_decode=arena, demote_on_deadline=True))
+
+
+def test_fleet_arena_exit_groups_read_once_per_round():
+    """Demoted requests split a round into several exit groups: tokens
+    stay bit-identical to the serial engine, every slot leaves a round
+    holding a ``(1, 1)`` int32 host ``next_tok``, and a round adds to
+    ``host_reads`` exactly its exit groups (one vector read each) plus
+    the prefill tokens it met still on the device."""
+    from repro.obs import SimProfiler
+    s_off, t_off, _ = _run_fleet(_demote_spec(False))
+    sc = Simulation(_demote_spec(True)).build()
+    eng = sc.engine
+    eng.profiler = prof = SimProfiler()
+    rounds = []
+    orig = eng._decode_real_arena
+
+    def traced(edge, reqs):
+        on_device = sum(not isinstance(r.next_tok, np.ndarray)
+                        for r in reqs)
+        reads0, calls0 = prof.host_reads, eng.stepper.arena_calls
+        orig(edge, reqs)
+        groups = eng.stepper.arena_calls - calls0
+        rounds.append((groups, on_device, prof.host_reads - reads0))
+        for r in reqs:
+            assert isinstance(r.next_tok, np.ndarray)
+            assert r.next_tok.shape == (1, 1)
+            assert r.next_tok.dtype == np.int32
+
+    eng._decode_real_arena = traced
+    s_on = eng.run(sc.workload).summary()
+    t_on = {r.rid: list(r.tokens) for r in sc.workload}
+    assert t_on == t_off
+    assert json.dumps(s_on, sort_keys=True) == \
+        json.dumps(s_off, sort_keys=True)
+    assert all(reads == groups + on_device
+               for groups, on_device, reads in rounds)
+    assert max(groups for groups, _, _ in rounds) >= 2
+    assert any(groups >= 2 and on_device == 0
+               for groups, on_device, _ in rounds)
 
 
 def _mobile_spec(arena: bool) -> ScenarioSpec:
@@ -335,19 +405,63 @@ def _mobile_spec(arena: bool) -> ScenarioSpec:
                                    arena_decode=arena))
 
 
+@pytest.fixture(scope="module")
+def mobile_runs():
+    """The mobile fleet served serially and through the arena, the arena
+    run logging each handover's ship (request id, source edge,
+    destination edge, tokens done, ``next_tok``) and each admission
+    (request id, edge, ``next_tok``)."""
+    from repro.fleet.engine import FleetEngine
+    ships, admits = [], []
+    ship, admit = FleetEngine._ship, FleetEngine._admit_real
+
+    def logged_ship(self, req, src_eid, dec, *args):
+        ships.append((req.rid, src_eid, dec.primary, req.tokens_done,
+                      req.next_tok))
+        return ship(self, req, src_eid, dec, *args)
+
+    def logged_admit(self, edge, req):
+        admits.append((req.rid, edge.eid, req.next_tok))
+        return admit(self, edge, req)
+
+    off = _run_fleet(_mobile_spec(False))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(FleetEngine, "_ship", logged_ship)
+        mp.setattr(FleetEngine, "_admit_real", logged_admit)
+        on = _run_fleet(_mobile_spec(True))
+    return off, on, ships, admits
+
+
 @pytest.mark.slow
-def test_fleet_arena_equals_serial_under_handover():
+def test_fleet_arena_equals_serial_under_handover(mobile_runs):
     """Mobile BOCD fleet that actually hands requests over mid-stream
     (pinned handovers > 0): the extract -> ship -> re-admit motion keeps
     token streams and summaries bit-identical to the serial engine."""
-    s_off, t_off, _ = _run_fleet(_mobile_spec(False))
-    s_on, t_on, st_ = _run_fleet(_mobile_spec(True))
+    (s_off, t_off, _), (s_on, t_on, st_), _, _ = mobile_runs
     assert s_off.get("handovers", 0) > 0          # the pin with teeth
     assert t_on == t_off
     assert json.dumps(s_on, sort_keys=True) == \
         json.dumps(s_off, sort_keys=True)
     assert st_["arena"]["calls"] > 0
     assert st_["decode"]["padded_rows"] == 0
+
+
+def test_handover_resumes_from_host_token_on_another_edge(mobile_runs):
+    """A request extracted from an edge's arena after decode rounds
+    ships with the ``(1, 1)`` int32 host token its last round left,
+    is re-admitted into another edge's arena holding that same token,
+    and finishes with the serial engine's tokens."""
+    (_, t_off, _), (_, t_on, _), ships, admits = mobile_runs
+    moved = [s for s in ships if s[3] > 0]
+    assert moved
+    for rid, src, dst, done, tok in moved:
+        assert dst != src
+        assert isinstance(tok, np.ndarray)
+        assert tok.shape == (1, 1) and tok.dtype == np.int32
+        assert int(tok[0, 0]) == t_on[rid][done - 1]
+        assert [eid for r, eid, t in admits
+                if r == rid and t is tok] == [dst]
+        assert t_on[rid] == t_off[rid] and len(t_on[rid]) > done
 
 
 def test_arena_off_matches_pre_pr_goldens():
