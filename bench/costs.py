@@ -1,86 +1,44 @@
 """Operations and bytes that the model's work requires, from the
-configuration's shapes alone (bf16 weights and cache: 2 bytes an element).
+configuration's shapes alone.
 
-A decoder layer holds four attention projections and three feed-forward
-matrices; a token through ``n`` layers costs two operations per weight it
-meets, and attention over ``t`` cached positions costs ``4 * heads *
-head_dim * t`` per layer (scores and the weighted sum).  The head is tied
-to the embedding: ``2 * hidden * vocab`` per token that is read out.
+What a layer, a token or a prompt costs is the configuration's family's
+(``bench/families/<model_type>.py``); the counts the readers and tests
+call are forwarded to it.  This module keeps the least time of a count on
+a chip and the sum over a window.
 """
 from __future__ import annotations
 
 from typing import Dict
 
-BYTES = 2          # bfloat16
+from bench.harness import family
 
 
 def layer_matmul_params(cfg: Dict) -> int:
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    hq = cfg["num_attention_heads"] * cfg["head_dim"]
-    hkv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    return d * hq + 2 * d * hkv + hq * d + 3 * d * ff
-
-
-def layer_params(cfg: Dict) -> int:
-    return layer_matmul_params(cfg) + 2 * cfg["hidden_size"]   # two norms
-
-
-def padded_vocab(cfg: Dict) -> int:
-    return (cfg["vocab_size"] + 127) // 128 * 128
+    return family(cfg).layer_matmul_params(cfg)
 
 
 def weight_bytes(cfg: Dict, layers: int = None) -> int:
-    """Bytes of the weights of ``layers`` decoder layers; with ``layers``
-    left out, of the whole model as held (layers, embedding with its
-    padding rows, final and exit norms)."""
-    if layers is not None:
-        return layers * layer_params(cfg) * BYTES
-    d = cfg["hidden_size"]
-    return (cfg["num_hidden_layers"] * layer_params(cfg)
-            + padded_vocab(cfg) * d + (1 + cfg["num_exits"]) * d) * BYTES
+    return family(cfg).weight_bytes(cfg, layers)
 
 
 def kv_bytes_per_position(cfg: Dict, layers: int = None) -> int:
-    """Cache bytes one position holds over ``layers`` layers (all when
-    left out): a key and a value of every KV head."""
-    n = cfg["num_hidden_layers"] if layers is None else layers
-    return n * 2 * cfg["num_key_value_heads"] * cfg["head_dim"] * BYTES
+    return family(cfg).kv_bytes_per_position(cfg, layers)
 
 
-def head_flops(cfg: Dict) -> int:
-    return 2 * cfg["hidden_size"] * cfg["vocab_size"]
-
-
-def attention_flops(cfg: Dict, layers: int, positions: int) -> int:
-    """Scores and weighted sum of one query over ``positions`` keys."""
-    return 4 * layers * cfg["num_attention_heads"] * cfg["head_dim"] \
-        * positions
+def decode_cache_bytes(cfg: Dict, layers: int, pos: int) -> int:
+    return family(cfg).decode_cache_bytes(cfg, layers, pos)
 
 
 def decode_flops(cfg: Dict, layers: int, pos: int, head: bool = True) -> int:
-    """One token decoded at cache position ``pos`` through ``layers``
-    layers (it attends to ``pos + 1`` positions), with its head."""
-    return (2 * layers * layer_matmul_params(cfg)
-            + attention_flops(cfg, layers, pos + 1)
-            + (head_flops(cfg) if head else 0))
+    return family(cfg).decode_flops(cfg, layers, pos, head)
 
 
 def prefill_flops(cfg: Dict, prompt_len: int, head: bool = True) -> int:
-    """A prompt through every layer (causal attention: position ``i``
-    attends to ``i + 1`` keys), with the head read at its last position."""
-    L, P = cfg["num_hidden_layers"], prompt_len
-    return (2 * P * L * layer_matmul_params(cfg)
-            + attention_flops(cfg, L, P * (P + 1) // 2)
-            + (head_flops(cfg) if head else 0))
+    return family(cfg).prefill_flops(cfg, prompt_len, head)
 
 
 def prefill_bytes(cfg: Dict, prompt_len: int) -> int:
-    """What a prefill must move at the least: every layer's weights read
-    once, the prompt's embedding rows read and its keys and values
-    written."""
-    L = cfg["num_hidden_layers"]
-    return (weight_bytes(cfg, L) + prompt_len * cfg["hidden_size"] * BYTES
-            + prompt_len * kv_bytes_per_position(cfg, L))
+    return family(cfg).prefill_bytes(cfg, prompt_len)
 
 
 def least_seconds(flops: float, nbytes: float, peaks: Dict) -> tuple:
@@ -98,11 +56,12 @@ def window_flops(cfg: Dict, reqs, t0: float, t1: float,
     prompt."""
     from bench.weights import exit_layers
     from bench.window import window_prefills, window_tokens
+    fam = family(cfg)
     depth = exit_layers(cfg) + [cfg["num_hidden_layers"]]
-    flops = sum(prefill_flops(cfg, r.prompt_len)
+    flops = sum(fam.prefill_flops(cfg, r.prompt_len)
                 for r in window_prefills(reqs, t0, t1))
     if decode:
-        flops += sum(decode_flops(cfg, depth[r.exit_point - 1],
-                                  r.prompt_len + i)
+        flops += sum(fam.decode_flops(cfg, depth[r.exit_point - 1],
+                                      r.prompt_len + i)
                      for r, i in window_tokens(reqs, t0, t1))
     return flops

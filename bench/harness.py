@@ -17,6 +17,7 @@ compared with the float32 reference (``bench.reference``).
 """
 from __future__ import annotations
 
+import functools
 import gc
 import importlib.util
 import json
@@ -33,6 +34,7 @@ from bench.window import WindowClosed
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
+FAMILIES = BENCH / "families"
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_trace"
 TRACE_SECONDS = 5.0
@@ -72,13 +74,29 @@ def device_peaks(kind: str) -> Dict:
     return table[kind]
 
 
-def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
+@functools.lru_cache(maxsize=None)
+def _module(path: Path):
+    """The Python file ``path``, loaded once."""
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{path.parent.name}_{path.stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    return _module(BENCH / "metrics" / f"{name}.py").read
+
+
+def family(cfg: Dict):
+    """The module of the configuration's model family,
+    ``families/<model_type>.py``: the program configuration, the weights'
+    layout, the float32 forward and the counts of that block."""
+    path = FAMILIES / f"{cfg['model_type']}.py"
+    if not path.is_file():
+        raise ValueError(f"model_type {cfg['model_type']!r}: no family "
+                         f"module {path}")
+    return _module(path)
 
 
 # --------------------------------------------------------------- program
@@ -86,19 +104,7 @@ def register_config(cfg: Dict) -> str:
     """Make ``repro.configs.get_config(cfg['name'])`` return the file's
     configuration in this process."""
     import repro.configs as configs
-    from repro.config import ModelConfig
-    if cfg["model_type"] != "granite":
-        raise ValueError(f"model_type {cfg['model_type']!r}: only the "
-                         "dense granite block is wired")
-    mc = ModelConfig(
-        name=cfg["name"], family="dense",
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], num_exits=cfg["num_exits"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        source=cfg["source"])
+    mc = family(cfg).model_config(cfg)
     if list(mc.exit_layer_indices()) != cfg["exit_after_layers"]:
         raise ValueError(f"the program places exits after layers "
                          f"{mc.exit_layer_indices()}, the file states "

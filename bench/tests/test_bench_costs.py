@@ -1,6 +1,7 @@
 """FLOP and byte counts against figures worked out by hand for
 granite-3-2b (40 layers, d 2048, 32/8 heads of 64, d_ff 8192, vocab
-49155 padded to 49280)."""
+49155 padded to 49280) and granite-3-8b-d20 (20 layers, d 4096, 32/8
+heads of 128, d_ff 12800)."""
 import json
 from pathlib import Path
 
@@ -10,6 +11,8 @@ from bench import costs
 
 CFG = json.loads((Path(__file__).resolve().parents[1] / "configs"
                   / "granite-3-2b.json").read_text())
+CFG_8B = json.loads((Path(__file__).resolve().parents[1] / "configs"
+                     / "granite-3-8b-d20.json").read_text())
 
 
 def test_weights_and_cache_bytes():
@@ -39,3 +42,17 @@ def test_flops():
     t, bound = costs.least_seconds(costs.prefill_flops(CFG, 1024),
                                    costs.prefill_bytes(CFG, 1024), peaks)
     assert bound == "compute" and t == pytest.approx(0.0262, rel=0.02)
+
+
+def test_8b_stage_weights_and_decode_cache_bytes():
+    # q,o 4096x4096 each; k,v 4096x1024 each; three 4096x12800; two norms
+    per_layer = 2 * 4096 * 4096 + 2 * 4096 * 1024 + 3 * 4096 * 12800
+    assert costs.layer_matmul_params(CFG_8B) == per_layer == 199_229_440
+    assert costs.weight_bytes(CFG_8B, 20) == 20 * (per_layer + 2 * 4096) * 2 \
+        == 7_969_505_280
+    # a token at position 1023 reads and writes K and V of 1024 positions:
+    # 20 layers x 2 x 8 heads x 128 x 2 bytes = 81,920 bytes a position
+    assert costs.decode_cache_bytes(CFG_8B, 20, 1023) == 1024 * 81_920 \
+        == 83_886_080
+    assert costs.decode_cache_bytes(CFG_8B, 4, 0) == 4 * 2 * 8 * 128 * 2 \
+        == 16_384

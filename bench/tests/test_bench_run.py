@@ -31,6 +31,9 @@ def test_exits_non_zero_without_a_tpu():
 def test_every_cell_finds_its_files(cell):
     spec = harness.cell_spec(cell)
     assert spec["config"]["name"] == spec["cell"]["config"]
+    family = harness.FAMILIES / f"{spec['config']['model_type']}.py"
+    assert family.is_file()
+    assert harness.family(spec["config"]).__file__ == str(family)
     assert spec["check"]["mean_logit_gap"] > 0
     assert NAME.match(cell)
     for m in BM["end_to_end"] + BM["per_layer"]:

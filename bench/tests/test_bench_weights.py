@@ -31,7 +31,8 @@ def test_whole_draw_equals_layer_by_layer():
     i = 0
     for seg, (first, n) in enumerate(weights.segments(cfg)):
         for j in range(n):
-            one = weights.layer(cfg, root, first + j, jnp.bfloat16)
+            one = harness.family(cfg).layer(cfg, root, first + j,
+                                            jnp.bfloat16)
             got = jax.tree_util.tree_map(lambda x: x[j],
                                          p["segments"][seg])
             for a, b in zip(jax.tree_util.tree_leaves(got),

@@ -10,9 +10,13 @@ the whole-model draw handed to the program.
 Matrices are uniform with the variance of ``1 / fan_in`` (the program's
 own initialiser draws a normal of that variance); norm weights are
 uniform in [0.75, 1.25), so a path that skipped a norm's scale would not
-agree with the reference.  The 125 rows that pad the vocabulary to a
-multiple of 128 are zero, so no served token lies outside the published
-vocabulary.
+agree with the reference.
+
+What the leaves are is the configuration's family's
+(``bench/families/<model_type>.py``): each layer's leaves, each run of
+layers between two exits as the program's segment holds it, the
+embedding and the final and exit norms.  This module draws the integers
+and puts the segments and heads together into the program's tree.
 """
 from __future__ import annotations
 
@@ -22,18 +26,14 @@ from typing import Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
-from bench import costs
-
-# leaf tags: the embedding, the final norm, the exit norms, then one tag
-# per layer (LAYER0 + layer index)
-EMBED, FINAL_NORM, EXIT_NORMS, LAYER0 = 1, 2, 3, 1000
+from bench.harness import family
 
 
 def exit_layers(cfg: Dict) -> List[int]:
     """1-based layer counts after which an exit head sits (the final head
-    excluded), evenly spaced in depth as the configuration states."""
-    n, L = cfg["num_exits"], cfg["num_hidden_layers"]
-    return [max(1, round(L * (i + 1) / (n + 1))) for i in range(n)]
+    excluded), as the configuration states them (the harness refuses a
+    program that places them elsewhere)."""
+    return list(cfg["exit_after_layers"])
 
 
 def segments(cfg: Dict) -> List[Tuple[int, int]]:
@@ -43,109 +43,76 @@ def segments(cfg: Dict) -> List[Tuple[int, int]]:
     return [(a, b - a) for a, b in zip(edges[:-1], edges[1:])]
 
 
-def layer_shapes(cfg: Dict) -> Dict[str, Dict[str, Tuple[tuple, int]]]:
-    """``{block: {leaf: (shape, fan_in)}}`` of one layer; ``fan_in`` 0
-    marks a norm weight."""
-    d, ff = cfg["hidden_size"], cfg["intermediate_size"]
-    hq = cfg["num_attention_heads"] * cfg["head_dim"]
-    hkv = cfg["num_key_value_heads"] * cfg["head_dim"]
-    return {"attn": {"wq": ((d, hq), d), "wk": ((d, hkv), d),
-                     "wv": ((d, hkv), d), "wo": ((hq, d), hq),
-                     "ln": ((d,), 0)},
-            "ffn": {"wg": ((d, ff), d), "wu": ((d, ff), d),
-                    "wd": ((ff, d), ff), "ln": ((d,), 0)}}
-
-
 def _ints(key, shape):
     bits = jax.random.bits(key, shape, jnp.uint32)
     return (bits >> 16).astype(jnp.int32) - 32768          # [-32768, 32768)
 
 
-def _matrix(key, shape, fan_in: int, dtype):
+def matrix(key, shape, fan_in: int, dtype):
     # uniform on [-a, a) has variance a^2 / 3: a = sqrt(3 / fan_in)
     c = math.sqrt(3.0 / fan_in) / 32768.0
     return (_ints(key, shape).astype(jnp.float32) * c).astype(dtype)
 
 
-def _norm(key, shape, dtype):
+def norm(key, shape, dtype):
     # (k + 2^17) * 2^-17 is exact in float32: [0.75, 1.25)
     k = _ints(key, shape) + 131072
     return (k.astype(jnp.float32) * (2.0 ** -17)).astype(dtype)
 
 
-def _leaf(key, shape, fan_in, dtype):
-    return _norm(key, shape, dtype) if fan_in == 0 else \
-        _matrix(key, shape, fan_in, dtype)
-
-
-def layer(cfg: Dict, root, index: int, dtype) -> Dict:
-    """One layer's weights (``{"attn": {...}, "ffn": {...}}``)."""
-    lkey = jax.random.fold_in(root, LAYER0 + index)
+def leaves(key, shapes: Dict[str, Dict[str, Tuple[tuple, int]]], dtype
+           ) -> Dict:
+    """The leaves of ``shapes`` (``{block: {leaf: (shape, fan_in)}}``,
+    ``fan_in`` 0 for a norm weight), leaf ``j`` in order drawn from
+    ``fold_in(key, j)``."""
     out, j = {}, 0
-    for block, leaves in layer_shapes(cfg).items():
+    for block, group in shapes.items():
         out[block] = {}
-        for name, (shape, fan) in leaves.items():
-            out[block][name] = _leaf(jax.random.fold_in(lkey, j), shape,
-                                     fan, dtype)
+        for name, (shape, fan) in group.items():
+            k = jax.random.fold_in(key, j)
+            out[block][name] = norm(k, shape, dtype) if fan == 0 else \
+                matrix(k, shape, fan, dtype)
             j += 1
     return out
-
-
-def embedding(cfg: Dict, root, dtype):
-    V, Vp, d = cfg["vocab_size"], costs.padded_vocab(cfg), cfg["hidden_size"]
-    table = _matrix(jax.random.fold_in(root, EMBED), (V, d), d, dtype)
-    return jnp.concatenate([table, jnp.zeros((Vp - V, d), dtype)])
-
-
-def final_norm(cfg: Dict, root, dtype):
-    return _norm(jax.random.fold_in(root, FINAL_NORM),
-                 (cfg["hidden_size"],), dtype)
-
-
-def exit_norm(cfg: Dict, root, e: int, dtype):
-    """The norm weight of exit head ``e`` (0-based, final head excluded)."""
-    key = jax.random.fold_in(jax.random.fold_in(root, EXIT_NORMS), e)
-    return _norm(key, (cfg["hidden_size"],), dtype)
 
 
 def root_key(seed: int):
     return jax.random.key(int(seed))
 
 
-def _draw(cfg_items: tuple, seed_key, dtype):
+def stack(layers: List[Dict]) -> Dict:
+    """Layers of one layout as one tree, each leaf stacked over them."""
+    return jax.tree_util.tree_map(lambda *x: jnp.stack(x), *layers)
+
+
+def _draw(fam, cfg_items: tuple, segs: tuple, root, dtype):
     cfg = dict(cfg_items)
-    segs = []
-    for first, n in segments(cfg):
-        layers = [layer(cfg, seed_key, first + i, dtype) for i in range(n)]
-        segs.append(jax.tree_util.tree_map(lambda *x: jnp.stack(x),
-                                           *layers))
-    params = {"embed": embedding(cfg, seed_key, dtype),
-              "segments": tuple(segs),
-              "final_norm": final_norm(cfg, seed_key, dtype)}
-    if cfg["num_exits"]:
+    params = {"embed": fam.embedding(cfg, root, dtype),
+              "segments": tuple(fam.segment(cfg, root, first, n, dtype)
+                                for first, n in segs),
+              "final_norm": fam.final_norm(cfg, root, dtype)}
+    if len(segs) > 1:
         params["exit_norms"] = jnp.stack(
-            [exit_norm(cfg, seed_key, e, dtype)
-             for e in range(cfg["num_exits"])])
+            [fam.exit_norm(cfg, root, e, dtype) for e in range(len(segs) - 1)])
     return params
 
 
-_draw_jit = jax.jit(_draw, static_argnums=(0, 2))
+_draw_jit = jax.jit(_draw, static_argnums=(0, 1, 2, 4))
 
 
-def key_items(cfg: Dict) -> tuple:
-    """The sizes the draw depends on, hashable (a static jit argument)."""
-    keys = ("hidden_size", "intermediate_size", "num_attention_heads",
-            "num_key_value_heads", "head_dim", "num_hidden_layers",
-            "num_exits", "vocab_size")
-    return tuple((k, cfg[k]) for k in keys)
+def _statics(cfg: Dict) -> tuple:
+    """The draw's static arguments: the family module, the sizes it
+    draws from (hashable) and the segments."""
+    fam = family(cfg)
+    return fam, fam.key_items(cfg), tuple(segments(cfg))
 
 
 def draw(cfg: Dict, seed: int, dtype=jnp.bfloat16) -> Dict:
     """The whole model's weights, drawn on the device in one compiled
     call, in ``dtype``, in the program's parameter layout."""
-    return _draw_jit(key_items(cfg), root_key(seed), dtype)
+    return _draw_jit(*_statics(cfg), root_key(seed), dtype)
 
 
 def abstract(cfg: Dict, dtype=jnp.bfloat16):
-    return jax.eval_shape(lambda k: _draw(key_items(cfg), k, dtype),
+    return jax.eval_shape(lambda k: _draw(*_statics(cfg), k, dtype),
                           root_key(0))
